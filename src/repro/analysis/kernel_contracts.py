@@ -193,18 +193,29 @@ def _table_fills(cap: _Capture) -> List[List[np.ndarray]]:
     Tables in this tree hold dim-0 block indices (expert/slot ids) for the
     operands their index maps gather; the §5/§7 clip contract bounds them
     to [0, E-1]. E differs per operand, so fills use the MINIMUM dim-0
-    block count over the non-prefetch operands — the tightest legal
-    extreme any spec could be asked to honor."""
+    block count over the operands the tables address (those whose dim-0
+    block index moves when the tables do; all operands when none does) —
+    the tightest legal extreme any spec could be asked to honor."""
     tables = cap.operands[:cap.num_prefetch]
     if not tables:
         return [[]]
-    emin = None
+    def dim0_block(imap, fill):
+        idx = imap(*(0,) * len(cap.grid),
+                   *[np.full(t.shape, fill, np.dtype(t.dtype))
+                     for t in tables])
+        return int((idx if isinstance(idx, tuple) else (idx,))[0])
+
+    counts, addressed = [], []
     for op, spec in zip(cap.operands[cap.num_prefetch:], cap.in_specs):
         bs = _block_shape(spec, op.shape)
-        if bs and bs[0] and op.shape:
-            n0 = op.shape[0] // bs[0]
-            emin = n0 if emin is None else min(emin, n0)
-    hi = max((emin or 1) - 1, 0)
+        if not (bs and bs[0] and op.shape):
+            continue
+        counts.append(op.shape[0] // bs[0])
+        imap = getattr(spec, "index_map", None)
+        if imap is not None and dim0_block(imap, 0) != dim0_block(imap, 1):
+            addressed.append(counts[-1])
+    emin = min(addressed or counts or [1])
+    hi = max(emin - 1, 0)
     fills = []
     for v in (0, hi):
         fills.append([np.full(t.shape, v, np.dtype(t.dtype))

@@ -48,7 +48,7 @@ JIT_WRAPPERS = {
     "jax.lax.scan", "jax.lax.cond", "jax.lax.while_loop",
     "jax.lax.fori_loop", "jax.lax.switch", "jax.lax.map",
     "jax.experimental.pallas.pallas_call",
-    "jax.experimental.shard_map.shard_map",
+    "jax.shard_map",
 }
 # method names that jit their function argument regardless of receiver:
 # TraceGuard.wrap_jit(name, fn, ...) is the engine's registration point for

@@ -28,11 +28,24 @@ the trace counter.
 """
 from __future__ import annotations
 
+import re
 from typing import Any, Callable, Dict, Optional
 
 import jax
 
 __all__ = ["TraceGuard", "TraceGuardError"]
+
+
+# what ``jax.transfer_guard("disallow")`` raises: a JaxRuntimeError whose
+# message names the refused direction. Other runtime errors (a device fault
+# whose text happens to mention a transfer) are not the guard's to absorb.
+_GUARD_REFUSAL = re.compile(
+    r"Disallowed (host-to-device|device-to-host|device-to-device) transfer")
+
+
+def _is_guard_refusal(e: Exception) -> bool:
+    return (isinstance(e, jax.errors.JaxRuntimeError)
+            and _GUARD_REFUSAL.search(str(e)) is not None)
 
 
 class TraceGuardError(RuntimeError):
@@ -102,8 +115,8 @@ class TraceGuard:
         try:
             with jax.transfer_guard("disallow"):
                 return fn(*args)
-        except Exception as e:  # transfer-guard violations are plain errors
-            if "transfer" not in str(e).lower():
+        except jax.errors.JaxRuntimeError as e:
+            if not _is_guard_refusal(e):
                 raise
             self.counters["implicit_transfers"] += 1
             if self.mode == "strict":

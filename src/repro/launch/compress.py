@@ -29,6 +29,7 @@ from repro import configs
 from repro.core import calibration as CAL
 from repro.core import compress as CMP
 from repro.core import plan as PLAN
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as MD
 
 # ONE jitted loss fn for every evaluation in this process: the config rides
@@ -176,6 +177,7 @@ def main():
                          "capture + sharded solves); bit-for-bit equal to "
                          "the single-device run (DESIGN.md §6)")
     args = ap.parse_args()
+    enable_compile_cache()
     _, _, report = run(args.arch, args.method, args.merged_experts,
                        split=args.split, calib_batches=args.calib_batches,
                        eval_batches=args.eval_batches, plan_path=args.plan,

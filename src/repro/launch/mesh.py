@@ -9,6 +9,18 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """The one device-mesh constructor of the program.
+
+    Every axis is ``AxisType.Auto``: the sharding model is GSPMD with
+    constraints (``numerics.constrain``) and shard_map'd programs, which
+    ``Explicit`` axes (``jax.make_mesh``'s default) refuse."""
+    shape, axes = tuple(int(s) for s in shape), tuple(axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,27 +28,18 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod:  (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_abstract_mesh(axis_sizes, axis_names):
-    """Version-compat AbstractMesh constructor.
-
-    JAX <= 0.4.x takes ``AbstractMesh(shape_tuple=(("data", 16), ...))``;
-    newer releases take ``AbstractMesh(axis_sizes, axis_names)``. Spec
-    derivation (sharding rules, dry-run lowering) only needs shape + names,
-    so either form is equivalent.
-    """
-    import inspect
+    """Device-free mesh for spec derivation (sharding rules, dry-run
+    lowering), which needs only shape and names."""
     from jax.sharding import AbstractMesh
 
     axis_sizes = tuple(int(s) for s in axis_sizes)
     axis_names = tuple(axis_names)
     if len(axis_sizes) != len(axis_names):
         raise ValueError(f"{len(axis_sizes)} sizes vs {len(axis_names)} names")
-    params = list(inspect.signature(AbstractMesh.__init__).parameters)
-    if "shape_tuple" in params:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
     return AbstractMesh(axis_sizes, axis_names)
 
 
@@ -45,7 +48,7 @@ def make_host_mesh(shape=None, axes=("data", "model")):
     n = jax.device_count()
     if shape is None:
         shape = (n, 1) if len(axes) == 2 else (n,)
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def data_axes(mesh) -> tuple:
@@ -94,8 +97,8 @@ def make_compression_mesh(spec: str | None = None):
     Default: every device on the "data" axis (calibration capture is pure
     data-parallelism; the "model" axis only shards the solve stage)."""
     if spec is None:
-        return jax.make_mesh((jax.device_count(),), ("data",))
-    return jax.make_mesh(*parse_mesh_spec(spec))
+        return make_mesh((jax.device_count(),), ("data",))
+    return make_mesh(*parse_mesh_spec(spec))
 
 
 def mesh_shape_dict(mesh) -> dict:

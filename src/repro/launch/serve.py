@@ -25,7 +25,7 @@ import numpy as np
 
 from repro import configs
 from repro.launch import steps as ST
-from repro.launch.mesh import make_host_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as MD
 from repro.models.numerics import set_activation_mesh
 from repro.serving import Engine, EngineConfig, Request, poisson_trace
@@ -57,8 +57,8 @@ class FixedBatchServer:
         self.cfg = cfg if cfg is not None else (
             configs.get(sc.arch).reduced() if sc.reduced
             else configs.get(sc.arch))
-        mesh = make_host_mesh()
-        set_activation_mesh(mesh)
+        # single-device, like the engine it is the reference for
+        set_activation_mesh(None)
         self.params = params if params is not None else MD.init(
             self.cfg, jax.random.PRNGKey(sc.seed))
         s_max = sc.prompt_len + sc.max_new_tokens
@@ -106,6 +106,7 @@ def main():
     ap.add_argument("--rate", type=float, default=0.5,
                     help="Poisson arrival rate, requests per decode step")
     args = ap.parse_args()
+    enable_compile_cache()
 
     ec = EngineConfig(arch=args.arch, n_slots=args.n_slots, s_max=args.s_max,
                       prefill_buckets=(args.prompt_len,))

@@ -411,7 +411,6 @@ def make_slot_decode_mesh(cfg: ModelConfig, mesh, params, cache,
     """Mesh form of :func:`make_slot_decode` — same signature and contract,
     args per-slot-sharded over "data" (``params``/``cache`` are template
     trees used only for spec derivation)."""
-    from jax.experimental.shard_map import shard_map
     cfg_l = ep_serve_cfg(cfg, mesh, combine_wire_dtype)
     pspec, cspec, v, rep = _mesh_specs(mesh, params, cache)
     dp = int(mesh.shape.get("data", 1))
@@ -426,8 +425,8 @@ def make_slot_decode_mesh(cfg: ModelConfig, mesh, params, cache,
         return logits, jnp.stack([greedy, finite], axis=-1), \
             _restore_tab(cache, tab0)
 
-    return shard_map(fn, mesh=mesh, in_specs=(pspec, cspec, v, v, v),
-                     out_specs=(v, v, cspec), check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(pspec, cspec, v, v, v),
+                         out_specs=(v, v, cspec), check_vma=False)
 
 
 def make_slot_decode_multi_mesh(cfg: ModelConfig, k_steps: int,
@@ -439,7 +438,6 @@ def make_slot_decode_multi_mesh(cfg: ModelConfig, k_steps: int,
     "model" axis only — so all members of a collective group always take
     the same branch."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     cfg_l = ep_serve_cfg(cfg, mesh, combine_wire_dtype)
     inner = make_slot_decode_multi(cfg_l, k_steps, temperature)
     pspec, cspec, v, rep = _mesh_specs(mesh, params, cache)
@@ -451,9 +449,10 @@ def make_slot_decode_multi_mesh(cfg: ModelConfig, k_steps: int,
                                   eos, keys, poison)
         return block, act, _restore_tab(cache, tab0)
 
-    return shard_map(fn, mesh=mesh,
-                     in_specs=(pspec, cspec, v, v, v, v, v, v),
-                     out_specs=(P(None, "data"), v, cspec), check_rep=False)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(pspec, cspec, v, v, v, v, v, v),
+                         out_specs=(P(None, "data"), v, cspec),
+                         check_vma=False)
 
 
 def make_slot_admit_mesh(cfg: ModelConfig, mesh, params, cache) -> Callable:
@@ -462,7 +461,6 @@ def make_slot_admit_mesh(cfg: ModelConfig, mesh, params, cache) -> Callable:
     nothing from the cache, so its logits are exact everywhere, EP splitting
     the MoE work across "model"); only the KV/pos scatters are localized so
     each data shard keeps its own slots."""
-    from jax.experimental.shard_map import shard_map
     cfg_l = ep_serve_cfg(cfg, mesh)
     pspec, cspec, v, rep = _mesh_specs(mesh, params, cache)
 
@@ -474,9 +472,9 @@ def make_slot_admit_mesh(cfg: ModelConfig, mesh, params, cache) -> Callable:
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return logits, greedy, cache
 
-    return shard_map(fn, mesh=mesh,
-                     in_specs=(pspec, cspec, rep, rep, rep),
-                     out_specs=(rep, rep, cspec), check_rep=False)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(pspec, cspec, rep, rep, rep),
+                         out_specs=(rep, rep, cspec), check_vma=False)
 
 
 def make_slot_admit_paged_mesh(cfg: ModelConfig, mesh, params,
@@ -487,7 +485,6 @@ def make_slot_admit_paged_mesh(cfg: ModelConfig, mesh, params,
     finite garbage for those rows. Owner rows are masked in, summed over
     "data" (adding exact fp zeros), and the greedy lane recomputed from the
     reconciled logits."""
-    from jax.experimental.shard_map import shard_map
     cfg_l = ep_serve_cfg(cfg, mesh)
     pspec, cspec, v, rep = _mesh_specs(mesh, params, cache)
     dp = int(mesh.shape.get("data", 1))
@@ -503,9 +500,9 @@ def make_slot_admit_paged_mesh(cfg: ModelConfig, mesh, params,
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return logits, greedy, _restore_tab(cache, tab0)
 
-    return shard_map(fn, mesh=mesh,
-                     in_specs=(pspec, cspec, rep, rep, rep, rep),
-                     out_specs=(rep, rep, cspec), check_rep=False)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(pspec, cspec, rep, rep, rep, rep),
+                         out_specs=(rep, rep, cspec), check_vma=False)
 
 
 def make_slot_decode_spec_mesh(cfg: ModelConfig, draft_cfg: ModelConfig,
@@ -517,7 +514,6 @@ def make_slot_decode_spec_mesh(cfg: ModelConfig, draft_cfg: ModelConfig,
     compressed tables divide the same way) and both caches sharded with the
     slots. The shared paged block table is localized once per cache."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.serving.spec import build_slot_decode_spec
     cfg_l = ep_serve_cfg(cfg, mesh, combine_wire_dtype)
     dcfg_l = ep_serve_cfg(draft_cfg, mesh, combine_wire_dtype)
@@ -538,10 +534,10 @@ def make_slot_decode_spec_mesh(cfg: ModelConfig, draft_cfg: ModelConfig,
         return (block, still, _restore_tab(cache, tab0),
                 _restore_tab(draft_cache, dtab0))
 
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(pspec, dpspec, cspec, dcspec, v, v, v, v, v, v),
-        out_specs=(P(None, "data"), v, cspec, dcspec), check_rep=False)
+        out_specs=(P(None, "data"), v, cspec, dcspec), check_vma=False)
 
 
 def make_slot_admit_spec_mesh(cfg: ModelConfig, draft_cfg: ModelConfig,
@@ -550,7 +546,6 @@ def make_slot_admit_spec_mesh(cfg: ModelConfig, draft_cfg: ModelConfig,
     """Mesh form of :func:`make_slot_admit_spec` (dense caches): replicated
     dual prefill, localized scatters — the :func:`make_slot_admit_mesh`
     story applied to both models."""
-    from jax.experimental.shard_map import shard_map
     from repro.serving.spec import build_slot_admit_spec
     cfg_l = ep_serve_cfg(cfg, mesh)
     dcfg_l = ep_serve_cfg(draft_cfg, mesh)
@@ -573,10 +568,10 @@ def make_slot_admit_spec_mesh(cfg: ModelConfig, draft_cfg: ModelConfig,
         first = sample_tokens(logits, temperature, keys, lengths)
         return logits, first, cache, draft_cache
 
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(pspec, dpspec, cspec, dcspec, rep, rep, rep, rep),
-        out_specs=(rep, rep, cspec, dcspec), check_rep=False)
+        out_specs=(rep, rep, cspec, dcspec), check_vma=False)
 
 
 def make_slot_admit_spec_paged_mesh(cfg: ModelConfig, draft_cfg: ModelConfig,
@@ -587,7 +582,6 @@ def make_slot_admit_spec_paged_mesh(cfg: ModelConfig, draft_cfg: ModelConfig,
     localized suffix group; logits reconcile over "data" (the
     :func:`make_slot_admit_paged_mesh` masking) and the first token is
     re-sampled from the reconciled logits so it is exact on every shard."""
-    from jax.experimental.shard_map import shard_map
     from repro.serving.spec import build_slot_admit_spec_paged
     cfg_l = ep_serve_cfg(cfg, mesh)
     dcfg_l = ep_serve_cfg(draft_cfg, mesh)
@@ -615,7 +609,7 @@ def make_slot_admit_spec_paged_mesh(cfg: ModelConfig, draft_cfg: ModelConfig,
         return (logits, first, _restore_tab(cache, tab0),
                 _restore_tab(draft_cache, dtab0))
 
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(pspec, dpspec, cspec, dcspec, rep, rep, rep, rep, rep),
-        out_specs=(rep, rep, cspec, dcspec), check_rep=False)
+        out_specs=(rep, rep, cspec, dcspec), check_vma=False)

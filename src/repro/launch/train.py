@@ -26,6 +26,7 @@ from repro.data.pipeline import make_pipeline, DataState
 from repro.distributed import StragglerMonitor, ef_compressed
 from repro.launch import sharding as SH
 from repro.launch import steps as ST
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import model as MD
 from repro.models.numerics import set_activation_mesh
@@ -146,6 +147,7 @@ def main():
         else:
             ap.add_argument(name, type=type(f.default), default=f.default)
     args = ap.parse_args()
+    enable_compile_cache()
     tc = TrainConfig(**{f.name: getattr(args, f.name)
                         for f in dataclasses.fields(TrainConfig)})
     out = train(tc)

@@ -81,6 +81,7 @@ block, and speculative — and across scheduling differences between them.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import heapq
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -92,7 +93,6 @@ import numpy as np
 from repro import configs
 from repro.core import errors as ERR
 from repro.launch import steps as ST
-from repro.launch.mesh import make_host_mesh
 from repro.models import model as MD
 from repro.models.numerics import set_activation_mesh
 from repro.serving.faults import FaultPlan
@@ -210,7 +210,9 @@ class EngineConfig:
     # all-to-all pair-exchange dispatch of ``models/moe_ep`` — and the
     # "data" axis shards slots + KV, so attention never crosses the wire.
     # Token-for-token identical to the single-device engine under the
-    # default fp32 combine wire.
+    # default fp32 combine wire where both run the jnp oracles (CPU). On a
+    # TPU they differ at bf16 level: the single-device prefill's grouped
+    # kernel sums f in blocks, the EP gather kernel does not.
     mesh: Optional[str] = None
     # EP combine-wire dtype: "fp32" (bitwise-exact return all-to-all) or
     # "int8" (``distributed.compressed_psum`` of the pair-output table —
@@ -283,29 +285,40 @@ class Engine:
         # form. Activation sharding constraints (numerics.constrain) are
         # GSPMD-only and illegal inside shard_map bodies, so mesh mode
         # clears the activation mesh — the mesh programs manage layout
-        # explicitly via their in/out specs.
+        # explicitly via their in/out specs. Without ec.mesh the engine is
+        # single-device and sets no activation mesh either: a host-wide one
+        # would make its programs GSPMD over every chip, and the TPU
+        # compiler cannot partition the Pallas kernels inside them.
         self._mesh = None
         if ec.mesh is not None:
-            from repro.launch.mesh import parse_mesh_spec
-            shape, axes = parse_mesh_spec(ec.mesh)
-            self._mesh = jax.make_mesh(shape, axes)
-            set_activation_mesh(None)
-        else:
-            mesh = make_host_mesh()
-            set_activation_mesh(mesh)
+            from repro.launch.mesh import make_mesh, parse_mesh_spec
+            self._mesh = make_mesh(*parse_mesh_spec(ec.mesh))
+        set_activation_mesh(None)
         self._dp = (1 if self._mesh is None
                     else int(self._mesh.shape.get("data", 1)))
         if ec.n_slots % self._dp:
             raise ValueError(
                 f"n_slots={ec.n_slots} must divide evenly over the mesh "
                 f"'data' axis ({self._dp}): slots and their KV shard there")
-        self.params = params if params is not None else MD.init(
-            cfg, jax.random.PRNGKey(ec.seed))
-        if self._mesh is not None:
+        if self._mesh is None:
+            self.params = params if params is not None else MD.init(
+                cfg, jax.random.PRNGKey(ec.seed))
+        else:
+            # seeded params are built sharded by the init program itself, so
+            # no device ever holds the whole model; given params only move.
+            # The jitted init may round a rare element differently from the
+            # eager one: engines meant to agree are handed the same params.
             from repro.launch import sharding as SH
-            SH.validate_ep_params(self.params, self._mesh)
-            self.params = jax.device_put(self.params, SH.named(
-                SH.serve_param_pspecs(self.params, self._mesh), self._mesh))
+            init = functools.partial(MD.init, cfg)
+            key = jax.random.PRNGKey(ec.seed)
+            shapes = params if params is not None else jax.eval_shape(
+                init, key)
+            SH.validate_ep_params(shapes, self._mesh)
+            shardings = SH.named(SH.serve_param_pspecs(shapes, self._mesh),
+                                 self._mesh)
+            self.params = (jax.device_put(params, shardings)
+                           if params is not None else
+                           jax.jit(init, out_shardings=shardings)(key))
 
         # host<->device crossing telemetry: device_calls counts jitted
         # dispatches, host_syncs counts device->host readbacks, tokens_out
@@ -504,6 +517,18 @@ class Engine:
         from repro.launch import sharding as SH
         return jax.device_put(cache, SH.named(
             SH.slot_cache_pspecs(cache, self._mesh), self._mesh))
+
+    def _slot_inputs(self, *host_arrays):
+        """Per-slot host arrays onto the device(s) the decode programs read
+        them from — an explicit host->device put, made before the trace
+        guard arms. In mesh mode they land sharded over "data" (the decode
+        programs' in_specs): a put to one chip would leave the guarded call
+        a device-to-device reshard, which the guard refuses."""
+        if self._mesh is None:
+            return tuple(jnp.asarray(a) for a in host_arrays)
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        sh = NamedSharding(self._mesh, P("data"))
+        return tuple(jax.device_put(a, sh) for a in host_arrays)
 
     @property
     def mesh(self):
@@ -870,9 +895,8 @@ class Engine:
             # host->device conversions happen HERE, before the guard arms:
             # inside the guarded call every argument is already device-side
             self._sync_tab()
-            toks = jnp.asarray(self._last_tok)
-            act = jnp.asarray(self._active)
-            poison = jnp.asarray(self._poison_mask(1))
+            toks, act, poison = self._slot_inputs(
+                self._last_tok, self._active, self._poison_mask(1))
             logits, aux, self.cache = self._with_retries(
                 "decode", "slot_decode",
                 lambda: self._guard.run("slot_decode", self._decode,
@@ -937,10 +961,9 @@ class Engine:
         # convert np inputs OUTSIDE the guarded region (explicit H2D); the
         # guarded fused block itself must touch the host zero times
         self._sync_tab()
-        args = (self.params, self.cache, jnp.asarray(self._last_tok),
-                jnp.asarray(self._active), jnp.asarray(rem),
-                jnp.asarray(eos), jnp.asarray(self._slot_keys),
-                jnp.asarray(self._poison_mask(K)))
+        args = (self.params, self.cache) + self._slot_inputs(
+            self._last_tok, self._active, rem, eos, self._slot_keys,
+            self._poison_mask(K))
         block, _, self.cache = self._with_retries(
             "decode", "slot_decode_multi",
             lambda: self._guard.run("slot_decode_multi", self._decode_multi,
@@ -1004,11 +1027,10 @@ class Engine:
             rem[s] = req.max_new_tokens - len(req.out_tokens)
             eos[s] = -1 if req.eos_token is None else req.eos_token
         self._sync_tab()
-        args = (self.params, self.draft_params, self.cache, self.cache_draft,
-                jnp.asarray(self._last_tok), jnp.asarray(self._active),
-                jnp.asarray(rem), jnp.asarray(eos),
-                jnp.asarray(self._slot_keys),
-                jnp.asarray(self._poison_mask(K)))
+        args = (self.params, self.draft_params, self.cache,
+                self.cache_draft) + self._slot_inputs(
+            self._last_tok, self._active, rem, eos, self._slot_keys,
+            self._poison_mask(K))
         block, _, self.cache, self.cache_draft = self._with_retries(
             "decode", "slot_decode_spec",
             lambda: self._guard.run("slot_decode_spec", self._decode_spec,
@@ -1428,10 +1450,13 @@ class Engine:
         when it changed. This is an EXPLICIT host->device transfer issued
         outside the guarded jitted calls — the table rides into them as an
         ordinary device argument, so the trace guard's implicit-transfer
-        check stays clean. Both pools (full + draft) share the one table."""
+        check stays clean. Both pools (full + draft) share the one table.
+        It lands with the sharding of the table it replaces: a device
+        program's output carries its mesh in its type, and an argument
+        whose type flips between calls would retrace the program."""
         if self._alloc is None or not self._tab_dirty:
             return
-        tab = jnp.asarray(self._alloc.tab)
+        tab = jax.device_put(self._alloc.tab, self.cache["tab"].sharding)
         self.cache = dict(self.cache, tab=tab)
         if self.cache_draft is not None:
             self.cache_draft = dict(self.cache_draft, tab=tab)

@@ -317,6 +317,27 @@ def test_contract_checker_oob_clip_tables():
     assert any(f.check == "bounds" for f in _findings(bad))
 
 
+def test_contract_checker_table_extreme_ignores_unaddressed_operands():
+    """The table's extreme comes from the operands it addresses: a one-block
+    token tile beside an E-row expert table must not shrink the fill to 0
+    and hide an off-by-one expert index (the tiled gather kernel's shape)."""
+    E, d = 4, 64
+    table = jax.ShapeDtypeStruct((2,), jnp.int32)
+    x = jax.ShapeDtypeStruct((16, d), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((E, d, d), jnp.bfloat16)
+
+    def cap(shift):
+        return _capture(
+            grid=(2,), num_prefetch=1,
+            in_specs=(_spec((16, d), lambda i, ix: (0, 0)),
+                      _spec((1, d, d), lambda i, ix: (ix[i] + shift, 0, 0))),
+            operands=(table, x, w),
+            out_spec=_spec((16, d), lambda i, ix: (0, 0)),
+            out_shape=jax.ShapeDtypeStruct((16, d), jnp.bfloat16))
+    assert not any(f.check == "bounds" for f in _findings(cap(0)))
+    assert any(f.check == "bounds" for f in _findings(cap(1)))
+
+
 # ---------------------------------------------------------------------------
 # trace guard
 # ---------------------------------------------------------------------------
@@ -364,6 +385,27 @@ def test_trace_guard_strict_raises_on_transfer():
     g.run("f", jitted, jnp.arange(4))
     with pytest.raises(TraceGuardError, match="implicit"):
         g.run("f", jitted, np.arange(4))
+
+
+@pytest.mark.parametrize("err", [
+    RuntimeError("device lost during transfer"),
+    jax.errors.JaxRuntimeError("INTERNAL: DMA transfer to HBM failed"),
+])
+def test_trace_guard_reraises_device_errors_naming_transfer(err):
+    """Only the transfer guard's own refusal is counted and re-run; a
+    device error whose text mentions a transfer propagates, run once."""
+    g = TraceGuard("count")
+    jitted = g.wrap_jit("f", lambda x: x + 1, expected_traces=1)
+    g.run("f", jitted, jnp.arange(4))
+    calls = []
+
+    def failing(x):
+        calls.append(x)
+        raise err
+
+    with pytest.raises(type(err), match="transfer"):
+        g.run("f", failing, jnp.arange(4))
+    assert len(calls) == 1 and g.counters["implicit_transfers"] == 0
 
 
 def test_trace_guard_off_mode_is_plain_jit():

@@ -168,6 +168,28 @@ def test_ep_validation_fails_fast():
 
 @pytest.mark.distributed
 @needs_devices
+def test_mesh_engine_strict_guard_across_decode_blocks():
+    """Per-slot decode inputs land sharded over "data" before the guarded
+    call: under the strict trace guard a mesh engine decodes several fused
+    blocks without the device-to-device reshard the guard refuses."""
+    from repro import configs
+    from repro.serving.engine import Engine, EngineConfig
+
+    cfg = configs.get("qwen3-moe-30b-a3b").reduced()
+    eng = Engine(EngineConfig(n_slots=4, s_max=32, prefill_buckets=(16,),
+                              decode_block=4, trace_guard="strict",
+                              mesh="data=2,model=2"), cfg=cfg)
+    rng = np.random.default_rng(5)
+    for _ in range(4):
+        eng.submit(rng.integers(0, cfg.vocab_size, size=16),
+                   max_new_tokens=10)
+    done = eng.run()
+    assert [r.finish_reason for r in done] == ["length"] * 4
+    assert eng.counters["implicit_transfers"] == 0
+
+
+@pytest.mark.distributed
+@needs_devices
 def test_sharded_allocator_partitions_block_ranges():
     """With n_shards > 1 every slot's blocks stay inside its shard's block
     range, prefix chains never cross shards, and deferral is per-shard
@@ -256,3 +278,31 @@ def test_crash_recovery_drill_token_identical(tmp_path):
     assert set(post) | {rec["uid"] for rec in pre_crash} == set(ref), \
         "some requests were lost across the crash"
     assert len(post) > 0                        # the restore really resumed
+
+
+def test_mesh_engine_builds_seeded_params_sharded():
+    """A mesh engine given no params builds them through the init program's
+    out-shardings (so no device first holds the whole model), and a
+    mesh-free engine handed the same arrays serves the same tokens."""
+    from jax.sharding import NamedSharding
+    from repro import configs
+    from repro.serving.engine import Engine, EngineConfig
+
+    cfg = configs.get("qwen3-moe-30b-a3b").reduced()
+    ec = dict(n_slots=2, s_max=32, prefill_buckets=(16,))
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, cfg.vocab_size, size=16) for _ in range(2)]
+
+    def serve(eng):
+        for p in prompts:
+            eng.submit(p, max_new_tokens=4)
+        return [list(r.out_tokens) for r in sorted(eng.run(),
+                                                   key=lambda r: r.uid)]
+
+    mesh_eng = Engine(EngineConfig(**ec, mesh="data=1,model=1"), cfg=cfg)
+    tables = mesh_eng.params["stack"]["moe"]["wg"]
+    assert isinstance(tables.sharding, NamedSharding)
+    assert tables.sharding.spec[1] == "model"       # [L, E, d, f]: E on model
+    got = serve(mesh_eng)
+    ref = serve(Engine(EngineConfig(**ec), cfg=cfg, params=mesh_eng.params))
+    assert got == ref

@@ -227,6 +227,7 @@ def _gather_inputs(T, d, f, E, k, dtype, seed=0):
     (1, 16, 16, 4, 1),      # single token, single expert
     (8, 32, 48, 8, 3),      # k > 2
     (3, 16, 32, 2, 2),      # tiny expert table
+    (20, 16, 16, 8, 2),     # rows span several token tiles
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_gather_swiglu(T, d, f, E, k, dtype):
