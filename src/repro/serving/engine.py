@@ -70,7 +70,22 @@ Design (decode dataflow details in DESIGN.md §7):
 
 The clock is pluggable: ``clock='steps'`` interprets ``arrival_time`` in
 decode-step units (deterministic — used by tests and the CPU benchmark),
-``clock='wall'`` in seconds.
+``clock='wall'`` in seconds. Under the wall clock a ``now`` passed to a
+step call anchors the engine's clock to the caller's, and each request is
+stamped on it: ``t_admitted`` just before its group's admission call,
+``t_first_token`` after that call's first-token readback, ``t_finished``
+after the readback of the block it finished in.
+
+Tracing: each fused call emits ``jax.profiler.TraceAnnotation`` host spans
+on the profiler's clock, one per phase and never per slot or token:
+``engine.step_block`` (args ``active``, ``pending`` at entry) holds
+``engine.admit`` (due scan, shedding, reservation, grouping; ``admitted``),
+one ``engine.admit_group`` per prefill group (``pad``, ``rows``,
+``rows_padded``, ``real_tokens``: host inputs, dispatch, first-token
+readback, bookkeeping), ``engine.decode_inputs``, ``engine.decode_block``
+(dispatch through readback; ``steps`` in which a slot emitted, ``rows``)
+and ``engine.commit`` (token loop, evictions, snapshot; ``tokens``,
+``evicted``). With no profiler running a span costs about a microsecond.
 
 Sampling keys: every request gets the key ``fold_in(PRNGKey(seed+1), uid)``
 at admission and tokens draw Gumbel noise indexed by their own sequence
@@ -100,6 +115,10 @@ from repro.serving.paging import PagedAllocator
 from repro.serving.spec import (build_slot_admit_spec,
                                 build_slot_admit_spec_paged,
                                 build_slot_decode_spec)
+
+# host spans on the profiler's clock; a span costs about a microsecond when
+# no profiler runs (module docstring, "Tracing")
+_span = jax.profiler.TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -888,7 +907,7 @@ class Engine:
         Returns the requests that finished during this step. This is the
         step-at-a-time reference loop; :meth:`step_block` is the fused
         production path (``run`` picks by ``decode_block``)."""
-        now = self._now() if now is None else now
+        now = self._anchor(now)
         finished = self._admit(now)
         quarantined: List[Request] = []
         if self._active.any():
@@ -918,10 +937,11 @@ class Engine:
                     # carry the sentinel inside their one block transfer
                     aux_np = np.asarray(aux)
                     self.counters["host_syncs"] += 1
+            t_read = self._stamp(now)
             for slot in np.flatnonzero(self._active):
                 req = self._slot_req[slot]
                 if sentinel and aux_np is not None and not aux_np[slot, 1]:
-                    quarantined.append(self._quarantine(slot, now))
+                    quarantined.append(self._quarantine(slot, t_read))
                     finished.append(req)
                     continue
                 tok = int(next_toks[slot])
@@ -929,7 +949,7 @@ class Engine:
                 self.counters["tokens_out"] += 1
                 self._last_tok[slot] = tok
                 if self._is_done(req, tok):
-                    self._evict(slot, now)
+                    self._evict(slot, t_read)
                     finished.append(req)
         self._step_count += 1
         self._maybe_snapshot()
@@ -938,71 +958,32 @@ class Engine:
 
     def step_block(self, now: float | None = None) -> List[Request]:
         """Admit due requests, then run ``decode_block`` fused decode steps
-        in ONE device call (DESIGN.md §7). Returns finished requests; their
-        ``t_finished`` is the block-start clock plus the inner step they
-        stopped at, so step accounting matches the per-step loop."""
-        now = self._now() if now is None else now
-        finished = self._admit(now)
-        K = self.ec.decode_block
-        if not self._active.any():
-            # nothing to decode: advance one step so arrival admission keeps
-            # fine-grained timing while the engine drains the future queue
-            self._step_count += 1
-            self._maybe_snapshot()
-            return finished
-        n = self.ec.n_slots
-        rem = np.zeros((n,), np.int32)
-        eos = np.full((n,), -1, np.int32)
-        slots = np.flatnonzero(self._active)
-        for s in slots:
-            req = self._slot_req[s]
-            rem[s] = req.max_new_tokens - len(req.out_tokens)
-            eos[s] = -1 if req.eos_token is None else req.eos_token
-        # convert np inputs OUTSIDE the guarded region (explicit H2D); the
-        # guarded fused block itself must touch the host zero times
-        self._sync_tab()
-        args = (self.params, self.cache) + self._slot_inputs(
-            self._last_tok, self._active, rem, eos, self._slot_keys,
-            self._poison_mask(K))
-        block, _, self.cache = self._with_retries(
-            "decode", "slot_decode_multi",
-            lambda: self._guard.run("slot_decode_multi", self._decode_multi,
-                                    *args))
-        self.counters["device_calls"] += 1
-        # ONE readback: [K, B, (tok, emit, finite)] — the numeric sentinel
-        # lane rides the same transfer (§12: zero additional host syncs)
-        block_np = np.asarray(block)
-        self.counters["host_syncs"] += 1
-        sentinel = self.ec.numeric_sentinel != "off"
-        quarantined: List[Request] = []
-        for s in slots:
-            req = self._slot_req[s]
-            for j in range(K):
-                if not block_np[j, s, 1]:
-                    break
-                t_j = now + j if self.ec.clock == "steps" else self._now()
-                if sentinel and not block_np[j, s, 2]:
-                    # tokens 0..j-1 already matched the fault-free stream;
-                    # token j was sampled from non-finite logits — truncate
-                    # there and quarantine the slot
-                    quarantined.append(self._quarantine(s, t_j))
-                    finished.append(req)
-                    break
-                tok = int(block_np[j, s, 0])
-                req.out_tokens.append(tok)
-                self.counters["tokens_out"] += 1
-                self._last_tok[s] = tok
-                if self._is_done(req, tok):
-                    # steps clock: finish = block start + inner step. Wall
-                    # clock has no per-inner-step timestamps (the block is
-                    # one device call) — stamp the post-block wall time.
-                    self._evict(s, t_j)
-                    finished.append(req)
-                    break
-        self._step_count += K
-        self._maybe_snapshot()
-        self._raise_if_strict(quarantined)
-        return finished
+        in ONE device call (DESIGN.md §7). Returns finished requests. Under
+        the step clock their ``t_finished`` is the block-start clock plus
+        the inner step they stopped at, so step accounting matches the
+        per-step loop; under the wall clock it is the block's readback."""
+        now = self._anchor(now)
+        with _span("engine.step_block", active=self.n_active,
+                   pending=self.n_pending):
+            finished = self._admit(now)
+            if not self._active.any():
+                return self._idle_step(finished)
+            K = self.ec.decode_block
+            slots, inputs = self._decode_inputs(K)
+            with _span("engine.decode_block", rows=len(slots)) as span:
+                block, _, self.cache = self._with_retries(
+                    "decode", "slot_decode_multi",
+                    lambda: self._guard.run(
+                        "slot_decode_multi", self._decode_multi,
+                        self.params, self.cache, *inputs))
+                # ONE readback: [K, B, (tok, emit, finite)] — the numeric
+                # sentinel lane rides the same transfer (§12: zero
+                # additional host syncs)
+                block_np = np.asarray(block)
+                span.set_metadata(steps=_emitting_steps(block_np, K))
+            self.counters["device_calls"] += 1
+            self.counters["host_syncs"] += 1
+            return self._commit(block_np, slots, K, now, finished)
 
     def step_spec(self, now: float | None = None) -> List[Request]:
         """Admit due requests, then run ONE fused draft/verify round
@@ -1011,63 +992,99 @@ class Engine:
         Returns finished requests. The step clock advances by ``spec_k``
         per round (the round's draft depth), so Poisson arrival traces in
         step units drain at the fused block's granularity, like §7."""
-        now = self._now() if now is None else now
-        finished = self._admit(now)
-        K = self.ec.spec_k
-        if not self._active.any():
-            self._step_count += 1
-            self._maybe_snapshot()
-            return finished
-        n = self.ec.n_slots
-        rem = np.zeros((n,), np.int32)
-        eos = np.full((n,), -1, np.int32)
-        slots = np.flatnonzero(self._active)
-        for s in slots:
-            req = self._slot_req[s]
-            rem[s] = req.max_new_tokens - len(req.out_tokens)
-            eos[s] = -1 if req.eos_token is None else req.eos_token
-        self._sync_tab()
-        args = (self.params, self.draft_params, self.cache,
-                self.cache_draft) + self._slot_inputs(
-            self._last_tok, self._active, rem, eos, self._slot_keys,
-            self._poison_mask(K))
-        block, _, self.cache, self.cache_draft = self._with_retries(
-            "decode", "slot_decode_spec",
-            lambda: self._guard.run("slot_decode_spec", self._decode_spec,
-                                    *args))
-        self.counters["device_calls"] += 1
-        # ONE readback: rows 0..K-1 = (token, emitted, finite) like
-        # step_block (sentinel lane over the VERIFY logits), row K =
-        # (accepted drafts, drafted, 1) per slot
-        block_np = np.asarray(block)
-        self.counters["host_syncs"] += 1
-        sentinel = self.ec.numeric_sentinel != "off"
-        quarantined: List[Request] = []
-        for s in slots:
-            req = self._slot_req[s]
-            for j in range(K):
-                if not block_np[j, s, 1]:
-                    break
-                t_j = now + j if self.ec.clock == "steps" else self._now()
-                if sentinel and not block_np[j, s, 2]:
-                    quarantined.append(self._quarantine(s, t_j))
-                    finished.append(req)
-                    break
-                tok = int(block_np[j, s, 0])
-                req.out_tokens.append(tok)
-                self.counters["tokens_out"] += 1
-                self._last_tok[s] = tok
-                if self._is_done(req, tok):
-                    self._evict(s, t_j)
-                    finished.append(req)
-                    break
-            n_match = int(block_np[K, s, 0])
-            drafted = int(block_np[K, s, 1])
+        now = self._anchor(now)
+        with _span("engine.step_block", active=self.n_active,
+                   pending=self.n_pending):
+            finished = self._admit(now)
+            if not self._active.any():
+                return self._idle_step(finished)
+            K = self.ec.spec_k
+            slots, inputs = self._decode_inputs(K)
+            with _span("engine.decode_block", rows=len(slots)) as span:
+                block, _, self.cache, self.cache_draft = self._with_retries(
+                    "decode", "slot_decode_spec",
+                    lambda: self._guard.run(
+                        "slot_decode_spec", self._decode_spec, self.params,
+                        self.draft_params, self.cache, self.cache_draft,
+                        *inputs))
+                # ONE readback: rows 0..K-1 = (token, emitted, finite) like
+                # step_block (sentinel lane over the VERIFY logits), row K =
+                # (accepted drafts, drafted, 1) per slot
+                block_np = np.asarray(block)
+                span.set_metadata(steps=_emitting_steps(block_np, K))
+            self.counters["device_calls"] += 1
+            self.counters["host_syncs"] += 1
+            n_match = int(block_np[K, slots, 0].sum())
+            drafted = int(block_np[K, slots, 1].sum())
             self.counters["tokens_drafted"] += drafted
             self.counters["tokens_accepted"] += n_match
             self.counters["tokens_rolled_back"] += drafted - n_match
-        self._step_count += K
+            return self._commit(block_np, slots, K, now, finished)
+
+    def _idle_step(self, finished: List[Request]) -> List[Request]:
+        """Nothing to decode: advance one step so arrival admission keeps
+        fine-grained timing while the engine drains the future queue."""
+        self._step_count += 1
         self._maybe_snapshot()
+        return finished
+
+    def _decode_inputs(self, k: int) -> Tuple[np.ndarray, tuple]:
+        """The active slots, and the per-slot device inputs of a ``k``-step
+        fused call: last token, active mask, tokens left, eos, sampling
+        keys, poison mask. Host->device conversions happen HERE, outside
+        the guarded call, which must touch the host zero times."""
+        with _span("engine.decode_inputs"):
+            n = self.ec.n_slots
+            rem = np.zeros((n,), np.int32)
+            eos = np.full((n,), -1, np.int32)
+            slots = np.flatnonzero(self._active)
+            for s in slots:
+                req = self._slot_req[s]
+                rem[s] = req.max_new_tokens - len(req.out_tokens)
+                eos[s] = -1 if req.eos_token is None else req.eos_token
+            self._sync_tab()
+            return slots, self._slot_inputs(
+                self._last_tok, self._active, rem, eos, self._slot_keys,
+                self._poison_mask(k))
+
+    def _commit(self, block_np: np.ndarray, slots: np.ndarray, k: int,
+                now: float, finished: List[Request]) -> List[Request]:
+        """Hand each slot the tokens it emitted in a ``k``-step readback
+        ``block_np`` [>=k, n_slots, (token, emitted, finite)], evict the
+        slots that finished or went non-finite, and advance the step clock
+        by ``k``. Step clock: a slot stopping at inner step ``j`` stamps
+        ``now + j``; wall clock: the readback's time."""
+        with _span("engine.commit") as span:
+            steps_clock = self.ec.clock == "steps"
+            t_read = self._stamp(now)
+            n_done, n_tok = len(finished), self.counters["tokens_out"]
+            sentinel = self.ec.numeric_sentinel != "off"
+            quarantined: List[Request] = []
+            for s in slots:
+                req = self._slot_req[s]
+                for j in range(k):
+                    if not block_np[j, s, 1]:
+                        break
+                    t_j = now + j if steps_clock else t_read
+                    if sentinel and not block_np[j, s, 2]:
+                        # tokens 0..j-1 already matched the fault-free
+                        # stream; token j was sampled from non-finite
+                        # logits — truncate there and quarantine the slot
+                        quarantined.append(self._quarantine(s, t_j))
+                        finished.append(req)
+                        break
+                    tok = int(block_np[j, s, 0])
+                    req.out_tokens.append(tok)
+                    self.counters["tokens_out"] += 1
+                    self._last_tok[s] = tok
+                    if self._is_done(req, tok):
+                        self._evict(s, t_j)
+                        finished.append(req)
+                        break
+            self._step_count += k
+            self._maybe_snapshot()
+            span.set_metadata(tokens=self.counters["tokens_out"] - n_tok,
+                              evicted=len(finished) - n_done)
         self._raise_if_strict(quarantined)
         return finished
 
@@ -1393,6 +1410,23 @@ class Engine:
             self._t0 = time.perf_counter()
         return time.perf_counter() - self._t0
 
+    def _anchor(self, now: float | None) -> float:
+        """The clock value a call starts at: the caller's ``now``, else the
+        engine's. Under the wall clock a given ``now`` re-anchors the
+        engine's clock to the caller's (``now`` plus the seconds since this
+        entry), so every stamp reads the caller's clock."""
+        if now is None:
+            return self._now()
+        if self.ec.clock == "wall":
+            self._t0 = time.perf_counter() - now
+        return now
+
+    def _stamp(self, now: float) -> float:
+        """A request stamp inside a call that started at ``now``: ``now``
+        itself under the step clock, the current reading under the wall
+        clock."""
+        return now if self.ec.clock == "steps" else self._now()
+
     def bucket_for(self, n: int) -> int:
         """Prefill pad length for an ``n``-token prompt (the jit
         specialization it will compile into): the smallest member of
@@ -1491,51 +1525,53 @@ class Engine:
         if self._done_early:
             finished.extend(self._done_early)
             self._done_early.clear()
-        free = [s for s in range(self.ec.n_slots) if not self._active[s]]
-        claimed: List[Tuple[Request, int, int]] = []
-        while self._pending and self._pending[0][0] <= now:
-            req = self._pending[0][-1]
-            dl = req.effective_deadline
-            if dl is not None and now > dl:
-                heapq.heappop(self._pending)
-                self._shed(req, now,
-                           "pool_pressure" if req.deferred else "deadline")
-                finished.append(req)
-                continue
-            if not free:
-                break
-            shared = 0
-            if self._faults is not None \
-                    and self._faults.exhausted(self._step_count):
-                # injected pool exhaustion: defer the head exactly like a
-                # real failed reservation (works in dense layout too)
-                req.deferred = True
-                break
-            if self._alloc is not None:
-                shared = self._alloc.admit(free[0], req.prompt,
-                                           self._reserve_rows(req))
-                if shared is None:
+        with _span("engine.admit") as span:
+            free = [s for s in range(self.ec.n_slots) if not self._active[s]]
+            claimed: List[Tuple[Request, int, int]] = []
+            while self._pending and self._pending[0][0] <= now:
+                req = self._pending[0][-1]
+                dl = req.effective_deadline
+                if dl is not None and now > dl:
+                    heapq.heappop(self._pending)
+                    self._shed(req, self._stamp(now),
+                               "pool_pressure" if req.deferred
+                               else "deadline")
+                    finished.append(req)
+                    continue
+                if not free:
+                    break
+                shared = 0
+                if self._faults is not None \
+                        and self._faults.exhausted(self._step_count):
+                    # injected pool exhaustion: defer the head exactly like
+                    # a real failed reservation (works in dense layout too)
                     req.deferred = True
-                    break                       # pool exhausted: defer head
-                self._tab_dirty = True
-            heapq.heappop(self._pending)
-            claimed.append((req, free.pop(0), shared))
-        if not claimed:
-            return finished
-        if self.ec.batch_admission:
+                    break
+                if self._alloc is not None:
+                    shared = self._alloc.admit(free[0], req.prompt,
+                                               self._reserve_rows(req))
+                    if shared is None:
+                        req.deferred = True
+                        break                   # pool exhausted: defer head
+                    self._tab_dirty = True
+                heapq.heappop(self._pending)
+                claimed.append((req, free.pop(0), shared))
             # paged grouping buckets by the SUFFIX length (the tokens the
             # admission forward actually runs); dense shared is always 0,
             # so this is the full prompt length there
-            groups: Dict[int, List[Tuple[Request, int, int]]] = {}
-            for req, slot, shared in claimed:
-                groups.setdefault(self.bucket_for(req.n_prompt - shared),
+            if self.ec.batch_admission:
+                by: Dict[int, List[Tuple[Request, int, int]]] = {}
+                for req, slot, shared in claimed:
+                    by.setdefault(self.bucket_for(req.n_prompt - shared),
                                   []).append((req, slot, shared))
-            for bucket in sorted(groups):
-                self._admit_group(bucket, groups[bucket], now, finished)
-        else:
-            for req, slot, shared in claimed:
-                self._admit_group(self.bucket_for(req.n_prompt - shared),
-                                  [(req, slot, shared)], now, finished)
+                groups = sorted(by.items())
+            else:
+                groups = [(self.bucket_for(req.n_prompt - shared),
+                           [(req, slot, shared)])
+                          for req, slot, shared in claimed]
+            span.set_metadata(admitted=len(claimed))
+        for bucket, group in groups:
+            self._admit_group(bucket, group, now, finished)
         return finished
 
     def _admit_group(self, bucket: int,
@@ -1558,68 +1594,77 @@ class Engine:
         Bp = 1
         while Bp < B:
             Bp *= 2
-        toks = np.zeros((Bp, bucket), np.int32)
-        lengths = np.ones((Bp,), np.int32)
-        slots = np.full((Bp,), self.ec.n_slots, np.int32)   # pads: OOB, dropped
-        pos0 = np.zeros((Bp,), np.int32)
-        keys = np.zeros((Bp, 2), np.uint32)
-        for i, (req, slot, shared) in enumerate(group):
-            suffix = req.prompt[shared:]
-            toks[i, :suffix.size] = suffix
-            lengths[i] = suffix.size
-            slots[i] = slot
-            pos0[i] = shared
-            # the request's sampling key, derived from its uid so the
-            # sampled stream is scheduling-independent (module docstring)
-            self._slot_keys[slot] = np.asarray(
-                jax.random.fold_in(self._key_base, req.uid), np.uint32)
-            keys[i] = self._slot_keys[slot]
-        self._sync_tab()
-        paged_args = ((jnp.asarray(pos0),) if self._alloc is not None
-                      else ())
-        if self.spec:
-            logits, first_dev, self.cache, self.cache_draft = \
-                self._with_retries(
-                    "admit", "slot_admit_spec",
-                    lambda: self._admit_spec(
-                        self.params, self.draft_params, self.cache,
-                        self.cache_draft, jnp.asarray(toks),
+        real = sum(req.n_prompt - shared for req, _, shared in group)
+        with _span("engine.admit_group", pad=bucket, rows=B, rows_padded=Bp,
+                   real_tokens=real):
+            toks = np.zeros((Bp, bucket), np.int32)
+            lengths = np.ones((Bp,), np.int32)
+            # pad rows: an out-of-bounds slot, dropped by the scatter
+            slots = np.full((Bp,), self.ec.n_slots, np.int32)
+            pos0 = np.zeros((Bp,), np.int32)
+            keys = np.zeros((Bp, 2), np.uint32)
+            for i, (req, slot, shared) in enumerate(group):
+                suffix = req.prompt[shared:]
+                toks[i, :suffix.size] = suffix
+                lengths[i] = suffix.size
+                slots[i] = slot
+                pos0[i] = shared
+                # the request's sampling key, derived from its uid so the
+                # sampled stream is scheduling-independent (module
+                # docstring)
+                self._slot_keys[slot] = np.asarray(
+                    jax.random.fold_in(self._key_base, req.uid), np.uint32)
+                keys[i] = self._slot_keys[slot]
+            self._sync_tab()
+            t_admitted = self._stamp(now)
+            paged_args = ((jnp.asarray(pos0),) if self._alloc is not None
+                          else ())
+            if self.spec:
+                logits, first_dev, self.cache, self.cache_draft = \
+                    self._with_retries(
+                        "admit", "slot_admit_spec",
+                        lambda: self._admit_spec(
+                            self.params, self.draft_params, self.cache,
+                            self.cache_draft, jnp.asarray(toks),
+                            jnp.asarray(lengths), jnp.asarray(slots),
+                            *paged_args, jnp.asarray(keys)))
+                self.counters["device_calls"] += 1
+                first = np.asarray(first_dev[:B])
+            else:
+                logits, greedy, self.cache = self._with_retries(
+                    "admit", "slot_admit",
+                    lambda: self._admit_step(
+                        self.params, self.cache, jnp.asarray(toks),
                         jnp.asarray(lengths), jnp.asarray(slots),
-                        *paged_args, jnp.asarray(keys)))
-            self.counters["device_calls"] += 1
-            first = np.asarray(first_dev[:B])
-        else:
-            logits, greedy, self.cache = self._with_retries(
-                "admit", "slot_admit",
-                lambda: self._admit_step(
-                    self.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(lengths), jnp.asarray(slots), *paged_args))
-            self.counters["device_calls"] += 1
-            # the first token occupies position ``n_prompt`` (= shared
-            # prefix rows + suffix length) — same noise index the device
-            # paths use for it
-            first = self._sample(logits[:B], greedy[:B], keys[:B],
-                                 pos0[:B] + lengths[:B])
-        self.counters["host_syncs"] += 1
-        if self._alloc is not None and self.ec.prefix_sharing:
-            # AFTER the device call: the rows now exist. Sharing begins at
-            # the NEXT admission cycle — every cycle's allocator
-            # reservations (lookup_prefix) run in _admit before any group's
-            # device call, so same-cycle duplicates never adopt each other
-            for req, slot, shared in group:
-                self._alloc.register_prefix(slot, req.prompt)
-        for i, (req, slot, shared) in enumerate(group):
-            tok = int(first[i])
-            req.out_tokens.append(tok)
-            self.counters["tokens_out"] += 1
-            req.t_admitted = now
-            req.t_first_token = now
-            self._slot_req[slot] = req
-            self._last_tok[slot] = tok
-            self._active[slot] = True
-            if self._is_done(req, tok):
-                self._evict(slot, now)
-                finished.append(req)
+                        *paged_args))
+                self.counters["device_calls"] += 1
+                # the first token occupies position ``n_prompt`` (= shared
+                # prefix rows + suffix length) — same noise index the
+                # device paths use for it
+                first = self._sample(logits[:B], greedy[:B], keys[:B],
+                                     pos0[:B] + lengths[:B])
+            t_first = self._stamp(now)
+            self.counters["host_syncs"] += 1
+            if self._alloc is not None and self.ec.prefix_sharing:
+                # AFTER the device call: the rows now exist. Sharing begins
+                # at the NEXT admission cycle — every cycle's allocator
+                # reservations (lookup_prefix) run in _admit before any
+                # group's device call, so same-cycle duplicates never adopt
+                # each other
+                for req, slot, shared in group:
+                    self._alloc.register_prefix(slot, req.prompt)
+            for i, (req, slot, shared) in enumerate(group):
+                tok = int(first[i])
+                req.out_tokens.append(tok)
+                self.counters["tokens_out"] += 1
+                req.t_admitted = t_admitted
+                req.t_first_token = t_first
+                self._slot_req[slot] = req
+                self._last_tok[slot] = tok
+                self._active[slot] = True
+                if self._is_done(req, tok):
+                    self._evict(slot, t_first)
+                    finished.append(req)
 
     def _evict(self, slot: int, now: float, status: str = "ok") -> None:
         req = self._slot_req[slot]
@@ -1692,6 +1737,12 @@ class Engine:
 # ---------------------------------------------------------------------------
 # arrival traces
 # ---------------------------------------------------------------------------
+
+def _emitting_steps(block_np: np.ndarray, k: int) -> int:
+    """Inner steps of a ``k``-step fused readback in which any slot emitted
+    a token."""
+    return int(block_np[:k, :, 1].any(axis=1).sum())
+
 
 def poisson_trace(n_requests: int, rate: float, seed: int = 0) -> np.ndarray:
     """Cumulative Poisson-process arrival times (rate = requests per clock
