@@ -14,8 +14,9 @@ invariants the kernels rely on, WITHOUT executing a single kernel:
   in-bounds, *including* scalar-prefetch tables evaluated at their extreme
   legal values 0 and E-1 — the §7 contract that OOB-clipped expert ids and
   dropped admission-pad rows keep every gather in-bounds by construction.
-  (Scalar tables in this tree always select dim 0 — expert/slot ids — so
-  E is the operand's dim-0 block count.)
+  (E, per table, is the block count of the operand dims the table selects:
+  dim 0 for expert/slot ids of one table, dims 0 and 1 for the layer index
+  and expert ids of a stacked ``[L, E, ...]`` one.)
 * **VMEM footprint**: the single-buffered sum of all VMEM-resident blocks
   plus scratch against a per-kernel budget (default 16 MiB, the per-core
   VMEM size). Known exceedances at full-size configs are *waived* with a
@@ -190,37 +191,44 @@ def _grid_points(grid: Tuple[int, ...], cap: int = 500_000):
 def _table_fills(cap: _Capture) -> List[List[np.ndarray]]:
     """Synthetic scalar-prefetch tables at extreme legal values.
 
-    Tables in this tree hold dim-0 block indices (expert/slot ids) for the
-    operands their index maps gather; the §5/§7 clip contract bounds them
-    to [0, E-1]. E differs per operand, so fills use the MINIMUM dim-0
-    block count over the operands the tables address (those whose dim-0
-    block index moves when the tables do; all operands when none does) —
-    the tightest legal extreme any spec could be asked to honor."""
+    Tables in this tree hold block indices (a layer index, expert or slot
+    ids) for the operands their index maps gather; the §5/§7 clip contract
+    bounds each to [0, n-1], with n its dims' block count. n differs per
+    operand, so each table's high fill is the MINIMUM block count over the
+    operand dims it addresses (those whose block index moves when that
+    table alone does; all operands' dim 0 when none does) — the tightest
+    legal extreme any spec could be asked to honor."""
     tables = cap.operands[:cap.num_prefetch]
     if not tables:
         return [[]]
-    def dim0_block(imap, fill):
-        idx = imap(*(0,) * len(cap.grid),
-                   *[np.full(t.shape, fill, np.dtype(t.dtype))
-                     for t in tables])
-        return int((idx if isinstance(idx, tuple) else (idx,))[0])
+    zeros = [np.zeros(t.shape, np.dtype(t.dtype)) for t in tables]
+    at0 = (0,) * len(cap.grid)
 
-    counts, addressed = [], []
-    for op, spec in zip(cap.operands[cap.num_prefetch:], cap.in_specs):
-        bs = _block_shape(spec, op.shape)
-        if not (bs and bs[0] and op.shape):
-            continue
-        counts.append(op.shape[0] // bs[0])
-        imap = getattr(spec, "index_map", None)
-        if imap is not None and dim0_block(imap, 0) != dim0_block(imap, 1):
-            addressed.append(counts[-1])
-    emin = min(addressed or counts or [1])
-    hi = max(emin - 1, 0)
-    fills = []
-    for v in (0, hi):
-        fills.append([np.full(t.shape, v, np.dtype(t.dtype))
-                      for t in tables])
-    return fills
+    def blocks(imap, fills):
+        idx = imap(*at0, *fills)
+        return tuple(int(v) for v in
+                     (idx if isinstance(idx, tuple) else (idx,)))
+
+    specs = [(op, spec, _block_shape(spec, op.shape))
+             for op, spec in zip(cap.operands[cap.num_prefetch:],
+                                 cap.in_specs)]
+    counts = [op.shape[0] // bs[0] for op, _, bs in specs
+              if bs and bs[0] and op.shape]
+    highs = []
+    for n, t in enumerate(tables):
+        ones = list(zeros)
+        ones[n] = np.ones(t.shape, np.dtype(t.dtype))
+        addressed = []
+        for op, spec, bs in specs:
+            imap = getattr(spec, "index_map", None)
+            if imap is None or not bs:
+                continue
+            lo, hi = blocks(imap, zeros), blocks(imap, ones)
+            addressed += [op.shape[d] // bs[d] for d in range(len(lo))
+                          if lo[d] != hi[d]]
+        highs.append(max(min(addressed or counts or [1]) - 1, 0))
+    return [zeros, [np.full(t.shape, h, np.dtype(t.dtype))
+                    for t, h in zip(tables, highs)]]
 
 
 def _check_capture(cap: _Capture, kernel: str, arch: str,
@@ -410,13 +418,14 @@ def _sds(shape, dtype):
     return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
 
 
-def _qexp(E: int, d: int, f: int):
+def _qexp(E: int, d: int, f: int, stack: tuple = ()):
     from repro.core.quant import QuantizedExpertTables
     i8, f32 = jnp.int8, jnp.float32
+    s = lambda *shape: stack + (E,) + shape  # noqa: E731
     return QuantizedExpertTables(
-        wg=_sds((E, d, f), i8), wg_scale=_sds((E, 1, f), f32),
-        wu=_sds((E, d, f), i8), wu_scale=_sds((E, 1, f), f32),
-        wd=_sds((E, f, d), i8), wd_scale=_sds((E, 1, d), f32))
+        wg=_sds(s(d, f), i8), wg_scale=_sds(s(1, f), f32),
+        wu=_sds(s(d, f), i8), wu_scale=_sds(s(1, f), f32),
+        wd=_sds(s(f, d), i8), wd_scale=_sds(s(1, d), f32))
 
 
 def _induced_cases(kind: str, cfg) -> List[Tuple[str, tuple]]:
@@ -455,12 +464,15 @@ def _induced_cases(kind: str, cfg) -> List[Tuple[str, tuple]]:
             x = _sds((T, d), dt)
             idx = _sds((T, k), jnp.int32)
             w = _sds((T, k), jnp.float32)
-            if kind == "gather":
-                cases.append((f"T{T}", (x, _sds((E, d, f), dt),
-                                        _sds((E, d, f), dt),
-                                        _sds((E, f, d), dt), idx, w)))
+            layer = _sds((), jnp.int32)
+            if kind == "gather":               # a decode stack's 2 layers
+                cases.append((f"T{T}", (x, _sds((2, E, d, f), dt),
+                                        _sds((2, E, d, f), dt),
+                                        _sds((2, E, f, d), dt), idx, w,
+                                        layer)))
             else:
-                cases.append((f"T{T}", (x, _qexp(E, d, f), idx, w)))
+                cases.append((f"T{T}", (x, _qexp(E, d, f, stack=(2,)), idx,
+                                        w, layer)))
         return cases
     if kind == "flash":
         if cfg.is_attention_free:

@@ -23,6 +23,16 @@ nothing. The combine weights sit whole in SMEM; the k contributions
 accumulate in an fp32 VMEM scratch, mirroring the ragged path's fp32
 scatter-add so the two dispatches agree (tests assert parity).
 
+The tables come stacked over layers, ``[L, E, d, f]`` / ``[L, E, f, d]``,
+with a scalar-prefetched ``layer`` index beside ``idx``: the index maps
+return ``(layer, idx[...], 0, 0)``, so the kernel fetches its experts
+straight out of the whole stack. A decode stack scans its layers with the
+tables held whole (``transformer._scan_layers``) because a per-layer slice
+of a scanned table is a dynamic slice, which XLA materializes before it can
+hand it to a custom call: every decode step would copy each layer's whole
+``[E, d, f]`` tables to read a few experts of them. One layer's unstacked
+``[E, d, f]`` tables are the L = 1 case (a free reshape, ``layer`` 0).
+
 The three double-buffered expert blocks exceed the default scoped VMEM
 limit at real widths (qwen3-moe: 3 x 2 x [2048, 768] bf16 ~ 18.9 MB), so
 each call sizes ``vmem_limit_bytes`` from its own blocks.
@@ -35,6 +45,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.ref import layer_stack
 
 F32 = jnp.float32
 _MIB = 1 << 20
@@ -63,12 +75,23 @@ def _pad_rows(x, idx, w, tm: int, E: int):
     return x, idx.reshape(-1), w.reshape(-1)
 
 
+def _layer(layer, n_layers: int):
+    """The layer index as the ``[1]`` int32 scalar-prefetch operand,
+    clipped into the stack like the expert ids."""
+    return jnp.clip(jnp.asarray(layer, jnp.int32), 0, n_layers - 1)[None]
+
+
 def _expert_map(tm: int, k: int):
     """Index map of the gathered expert blocks: row ``i*tm + r``'s j-th
-    expert."""
-    def index(i, j, r, ix):
-        return (ix[(i * tm + r) * k + j], 0, 0)
+    expert of layer ``ly[0]``."""
+    def index(i, j, r, ly, ix):
+        return (ly[0], ix[(i * tm + r) * k + j], 0, 0)
     return index
+
+
+def _tile_map(i, j, r, ly, ix):
+    """Index map of the token tiles (input rows and output)."""
+    return (i, 0)
 
 
 def _compiler_params(pipelined_bytes: int, temp_bytes: int):
@@ -88,8 +111,8 @@ def _row_of(x, r):
                    keepdims=True)
 
 
-def _kernel(idx_ref, x_ref, w_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref,
-            *, k: int, tm: int, n_tokens: int):
+def _kernel(ly_ref, idx_ref, x_ref, w_ref, wg_ref, wu_ref, wd_ref, o_ref,
+            acc_ref, *, k: int, tm: int, n_tokens: int):
     i, j, r = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     t = i * tm + r
 
@@ -100,13 +123,14 @@ def _kernel(idx_ref, x_ref, w_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref,
     @pl.when(t < n_tokens)
     def _row():
         x = _row_of(x_ref[...], r).astype(x_ref.dtype)      # [1, d]
-        g = jnp.dot(x, wg_ref[0], preferred_element_type=F32)
-        u = jnp.dot(x, wu_ref[0], preferred_element_type=F32)
+        g = jnp.dot(x, wg_ref[0, 0], preferred_element_type=F32)
+        u = jnp.dot(x, wu_ref[0, 0], preferred_element_type=F32)
         h = (jax.nn.silu(g) * u).astype(x.dtype)
         # downcast to the model dtype before the fp32-weighted combine — the
         # exact arithmetic of the ragged path (grouped matmul emits x.dtype
         # rows, the combine scatter-adds them in fp32)
-        y = jnp.dot(h, wd_ref[0], preferred_element_type=F32).astype(x.dtype)
+        y = jnp.dot(h, wd_ref[0, 0],
+                    preferred_element_type=F32).astype(x.dtype)
         rows = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 0)
         acc_ref[...] += jnp.where(rows == r,
                                   w_ref[t * k + j] * y.astype(F32), 0.0)
@@ -117,17 +141,20 @@ def _kernel(idx_ref, x_ref, w_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def gather_swiglu(x, wg, wu, wd, idx, w, interpret: bool = False):
-    """x: [T, d]; wg/wu: [E, d, f]; wd: [E, f, d]; idx: [T, k] int32 in REAL
-    expert space; w: [T, k] combine weights. Returns [T, d] where row t is
-    ``Σ_j w[t, j] · SwiGLU_{idx[t, j]}(x[t])``.
+def gather_swiglu(x, wg, wu, wd, idx, w, layer=0, interpret: bool = False):
+    """x: [T, d]; wg/wu: [L, E, d, f]; wd: [L, E, f, d] (or one layer's
+    [E, ...] tables, the L = 1 case); idx: [T, k] int32 in REAL expert
+    space; w: [T, k] combine weights; layer: int32 scalar, the layer of the
+    stack to read. Returns [T, d] where row t is
+    ``Σ_j w[t, j] · SwiGLU_{idx[t, j]}(x[t])`` on that layer's experts.
 
-    ``idx`` entries are clipped to [0, E): routing fails closed upstream
-    (``moe.route`` masks remap targets >= live, DESIGN.md §5), so the clip is
-    pure out-of-bounds defense for the weight-row gather, matching the
-    oracle."""
+    ``idx`` entries are clipped to [0, E) and ``layer`` to [0, L): routing
+    fails closed upstream (``moe.route`` masks remap targets >= live,
+    DESIGN.md §5), so the clip is pure out-of-bounds defense for the
+    weight-row gather, matching the oracle."""
+    wg, wu, wd = layer_stack(wg), layer_stack(wu), layer_stack(wd)
     T, d = x.shape
-    E, _, f = wg.shape
+    n_layers, E, _, f = wg.shape
     k = idx.shape[-1]
     if T == 0:
         return jnp.zeros((0, d), x.dtype)
@@ -137,16 +164,16 @@ def gather_swiglu(x, wg, wu, wd, idx, w, interpret: bool = False):
     expert = _expert_map(tm, k)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(Tp // tm, k, tm),
         in_specs=[
-            pl.BlockSpec((tm, d), lambda i, j, r, ix: (i, 0)),
+            pl.BlockSpec((tm, d), _tile_map),
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, d, f), expert),
-            pl.BlockSpec((1, d, f), expert),
-            pl.BlockSpec((1, f, d), expert),
+            pl.BlockSpec((1, 1, d, f), expert),
+            pl.BlockSpec((1, 1, d, f), expert),
+            pl.BlockSpec((1, 1, f, d), expert),
         ],
-        out_specs=pl.BlockSpec((tm, d), lambda i, j, r, ix: (i, 0)),
+        out_specs=pl.BlockSpec((tm, d), _tile_map),
         scratch_shapes=[pltpu.VMEM((tm, d), F32)],
     )
     isz = x.dtype.itemsize
@@ -158,11 +185,11 @@ def gather_swiglu(x, wg, wu, wd, idx, w, interpret: bool = False):
             3 * d * f * wg.dtype.itemsize + 2 * tm * d * isz,
             tm * d * 4 + 3 * tm * f * 4),
         interpret=interpret,
-    )(idx, xp, w, wg, wu, wd)
+    )(_layer(layer, n_layers), idx, xp, w, wg, wu, wd)
     return out[:T]
 
 
-def _kernel_q(idx_ref, x_ref, qg_ref, qu_ref, qd_ref,
+def _kernel_q(ly_ref, idx_ref, x_ref, qg_ref, qu_ref, qd_ref,
               sg_ref, su_ref, sd_ref, o_ref, *, tm: int, n_tokens: int):
     """Int8 variant of :func:`_kernel`: the three gathered weight blocks are
     int8 plus fp32 per-output-channel scale rows, dequantized in VMEM — one
@@ -187,9 +214,9 @@ def _kernel_q(idx_ref, x_ref, qg_ref, qu_ref, qd_ref,
     @pl.when(i * tm + r < n_tokens)
     def _row():
         x32 = _row_of(x_ref[...], r)                         # [1, d]
-        wg = qg_ref[0].astype(F32) * sg_ref[0]
-        wu = qu_ref[0].astype(F32) * su_ref[0]
-        wd = qd_ref[0].astype(F32) * sd_ref[0]
+        wg = qg_ref[0, 0].astype(F32) * sg_ref[0, 0]
+        wu = qu_ref[0, 0].astype(F32) * su_ref[0, 0]
+        wd = qd_ref[0, 0].astype(F32) * sd_ref[0, 0]
         g = jnp.dot(x32, wg)
         u = jnp.dot(x32, wu)
         h = jax.nn.silu(g) * u
@@ -198,17 +225,19 @@ def _kernel_q(idx_ref, x_ref, qg_ref, qu_ref, qd_ref,
         o_ref[0] = jnp.where(rows == r, y, o_ref[0])
 
 
-def gather_swiglu_q(x, qt, idx, w, interpret: bool = False):
+def gather_swiglu_q(x, qt, idx, w, layer=0, interpret: bool = False):
     """Int8 decode-mode gather SwiGLU. Same contract as
     :func:`gather_swiglu` with the weight tables replaced by a
     :class:`repro.core.quant.QuantizedExpertTables` (int8 tables + keepdim
-    fp32 scales); per token the kernel streams k int8 expert row-sets — the
-    decode hot loop's dominant HBM term at half the bf16 width. Bitwise
+    fp32 scales, stacked ``[L, E, ...]`` or one layer's ``[E, ...]``); per
+    token the kernel streams k int8 expert row-sets of layer ``layer`` —
+    the decode hot loop's dominant HBM term at half the bf16 width. Bitwise
     equal to ``ref.gather_swiglu_q`` in interpret mode. Deliberately
     UNJITTED, same reasoning as ``grouped_swiglu_q`` (production jits at
     the ``ops`` layer)."""
+    qt = jax.tree.map(layer_stack, qt)
     T, d = x.shape
-    E, _, f = qt.wg.shape
+    n_layers, E, _, f = qt.wg.shape
     k = idx.shape[-1]
     if T == 0:
         return jnp.zeros((0, d), x.dtype)
@@ -218,18 +247,19 @@ def gather_swiglu_q(x, qt, idx, w, interpret: bool = False):
     expert = _expert_map(tm, k)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(Tp // tm, k, tm),
         in_specs=[
-            pl.BlockSpec((tm, d), lambda i, j, r, ix: (i, 0)),
-            pl.BlockSpec((1, d, f), expert),
-            pl.BlockSpec((1, d, f), expert),
-            pl.BlockSpec((1, f, d), expert),
-            pl.BlockSpec((1, 1, f), expert),
-            pl.BlockSpec((1, 1, f), expert),
-            pl.BlockSpec((1, 1, d), expert),
+            pl.BlockSpec((tm, d), _tile_map),
+            pl.BlockSpec((1, 1, d, f), expert),
+            pl.BlockSpec((1, 1, d, f), expert),
+            pl.BlockSpec((1, 1, f, d), expert),
+            pl.BlockSpec((1, 1, 1, f), expert),
+            pl.BlockSpec((1, 1, 1, f), expert),
+            pl.BlockSpec((1, 1, 1, d), expert),
         ],
-        out_specs=pl.BlockSpec((1, tm, d), lambda i, j, r, ix: (j, i, 0)),
+        out_specs=pl.BlockSpec((1, tm, d),
+                               lambda i, j, r, ly, ix: (j, i, 0)),
         scratch_shapes=[],
     )
     isz = x.dtype.itemsize
@@ -241,7 +271,7 @@ def gather_swiglu_q(x, qt, idx, w, interpret: bool = False):
             3 * d * f + 4 * (2 * f + d) + 2 * tm * d * isz,
             3 * d * f * 4 + tm * d * 4 + 3 * tm * f * 4),
         interpret=interpret,
-    )(idx, xp, qt.wg, qt.wu, qt.wd,
+    )(_layer(layer, n_layers), idx, xp, qt.wg, qt.wu, qt.wd,
       qt.wg_scale, qt.wu_scale, qt.wd_scale)
     y = jnp.swapaxes(y, 0, 1)[:T]                            # [T, k, d]
     # the oracle's combine, verbatim: fp32 weights over model-dtype rows

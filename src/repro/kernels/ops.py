@@ -111,8 +111,10 @@ def grouped_swiglu(x, wg, wu, wd, group_sizes):
 
 @pallas_dispatch("decode_moe", contract={"kind": "gather",
                                          "quantized": False})
-def gather_swiglu(x, wg, wu, wd, idx, w):
-    return ref.gather_swiglu(x, wg, wu, wd, idx, w)
+def gather_swiglu(x, wg, wu, wd, idx, w, layer=0):
+    """Decode-mode gather SwiGLU over layer ``layer`` of stacked
+    ``[L, E, ...]`` expert tables, read in place (DESIGN.md §7)."""
+    return ref.gather_swiglu(x, wg, wu, wd, idx, w, layer)
 
 
 @pallas_dispatch("grouped_mlp", contract={"kind": "grouped_q",
@@ -124,9 +126,9 @@ def grouped_swiglu_q(x, qt, group_sizes):
 
 @pallas_dispatch("decode_moe", contract={"kind": "gather_q",
                                          "quantized": True})
-def gather_swiglu_q(x, qt, idx, w):
+def gather_swiglu_q(x, qt, idx, w, layer=0):
     """Int8 decode-mode gather SwiGLU over a ``QuantizedExpertTables``."""
-    return ref.gather_swiglu_q(x, qt, idx, w)
+    return ref.gather_swiglu_q(x, qt, idx, w, layer)
 
 
 # ---------------------------------------------------------------------------
@@ -149,19 +151,20 @@ def localize_expert_ids(idx, w, e_base, e_local: int):
     return jnp.clip(lid, 0, e_local - 1), jnp.where(mine, w, 0.0)
 
 
-def gather_swiglu_sharded(x, wg, wu, wd, idx, w, e_base):
-    """:func:`gather_swiglu` over one EP shard's expert-table slice.
+def gather_swiglu_sharded(x, wg, wu, wd, idx, w, e_base, layer=0):
+    """:func:`gather_swiglu` over one EP shard's expert-table slice
+    (``[L, E_local, ...]`` or one layer's ``[E_local, ...]``).
 
     Same per-row arithmetic; ``idx`` stays in GLOBAL expert space and is
     offset by ``e_base`` (this shard's first row) before the gather."""
-    lid, w = localize_expert_ids(idx, w, e_base, wg.shape[0])
-    return gather_swiglu(x, wg, wu, wd, lid, w)
+    lid, w = localize_expert_ids(idx, w, e_base, wg.shape[-3])
+    return gather_swiglu(x, wg, wu, wd, lid, w, layer)
 
 
-def gather_swiglu_q_sharded(x, qt, idx, w, e_base):
+def gather_swiglu_q_sharded(x, qt, idx, w, e_base, layer=0):
     """Int8 variant of :func:`gather_swiglu_sharded` (qexp table slice)."""
-    lid, w = localize_expert_ids(idx, w, e_base, qt.wg.shape[0])
-    return gather_swiglu_q(x, qt, lid, w)
+    lid, w = localize_expert_ids(idx, w, e_base, qt.wg.shape[-3])
+    return gather_swiglu_q(x, qt, lid, w, layer)
 
 
 @pallas_dispatch("flash_attention", extra_static=("causal",),

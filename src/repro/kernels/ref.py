@@ -45,26 +45,35 @@ def grouped_swiglu(x: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
     return ein("tf,tfd->td", h, wd[eid]).astype(x.dtype)
 
 
+def layer_stack(t):
+    """A layer stack ``[L, E, ...]``; one layer's ``[E, ...]`` table is the
+    L = 1 stack (the gather kernels' table contract, DESIGN.md §7)."""
+    return t if t.ndim == 4 else t[None]
+
+
 def gather_swiglu(x: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
-                  idx: jax.Array, w: jax.Array) -> jax.Array:
+                  idx: jax.Array, w: jax.Array, layer=0) -> jax.Array:
     """Decode-mode (gather-dispatch) MoE oracle.
 
-    x: [T, d]; wg/wu: [E, d, f]; wd: [E, f, d]; idx: [T, k] int32 REAL-expert
-    ids; w: [T, k] combine weights. Returns [T, d] with row t equal to
+    x: [T, d]; wg/wu: [L, E, d, f]; wd: [L, E, f, d] (or one layer's
+    [E, ...] tables, the L = 1 case); idx: [T, k] int32 REAL-expert ids;
+    w: [T, k] combine weights; layer: the layer of the stack to read.
+    Returns [T, d] with row t equal to
     ``Σ_j w[t, j] · SwiGLU_{idx[t, j]}(x[t])`` — the same per-row arithmetic
     as :func:`grouped_swiglu` on expert-sorted rows, evaluated token-major
     (no sort/bincount/scatter). The combine accumulates in fp32, mirroring
     the ragged path's scatter-add.
     """
+    wg, wu, wd = layer_stack(wg), layer_stack(wu), layer_stack(wd)
     T, d = x.shape
     k = idx.shape[-1]
-    E = wg.shape[0]
+    E = wg.shape[1]
     eid = jnp.clip(idx.reshape(-1), 0, E - 1)        # [T*k] token-major
     xr = jnp.repeat(x, k, axis=0)                    # [T*k, d]
-    g = ein("td,tdf->tf", xr, wg[eid])
-    u = ein("td,tdf->tf", xr, wu[eid])
+    g = ein("td,tdf->tf", xr, wg[layer, eid])
+    u = ein("td,tdf->tf", xr, wu[layer, eid])
     h = (jax.nn.silu(g) * u).astype(x.dtype)
-    y = ein("tf,tfd->td", h, wd[eid]).astype(x.dtype)
+    y = ein("tf,tfd->td", h, wd[layer, eid]).astype(x.dtype)
     out = jnp.sum(y.reshape(T, k, d).astype(F32)
                   * w.reshape(T, k, 1).astype(F32), axis=1)
     return out.astype(x.dtype)
@@ -105,24 +114,26 @@ def grouped_swiglu_q(x: jax.Array, qt, group_sizes: jax.Array) -> jax.Array:
 
 
 def gather_swiglu_q(x: jax.Array, qt, idx: jax.Array,
-                    w: jax.Array) -> jax.Array:
+                    w: jax.Array, layer=0) -> jax.Array:
     """Int8 decode-mode (gather-dispatch) oracle.
 
-    Row semantics of :func:`gather_swiglu` on the fp32-dequantized tables:
-    each (token, j) contribution is computed at fp32, downcast to
-    ``x.dtype`` (the same output rounding :func:`grouped_swiglu_q` applies,
-    so the int8 ragged and gather paths stay bitwise-consistent at
-    top_k = 2), then combined with fp32 weights."""
+    Row semantics of :func:`gather_swiglu` on the fp32-dequantized tables
+    (stacked ``[L, E, ...]`` or one layer's, as there): each (token, j)
+    contribution is computed at fp32, downcast to ``x.dtype`` (the same
+    output rounding :func:`grouped_swiglu_q` applies, so the int8 ragged
+    and gather paths stay bitwise-consistent at top_k = 2), then combined
+    with fp32 weights."""
+    qt = jax.tree.map(layer_stack, qt)
     T, d = x.shape
     k = idx.shape[-1]
-    E = qt.wg.shape[0]
-    wg32, wu32, wd32 = _dequant32(qt)
+    E = qt.wg.shape[1]
     eid = jnp.clip(idx.reshape(-1), 0, E - 1)
+    wg32, wu32, wd32 = _dequant32(jax.tree.map(lambda a: a[layer, eid], qt))
     xr = jnp.repeat(x, k, axis=0).astype(F32)
-    g = jnp.einsum("td,tdf->tf", xr, wg32[eid])
-    u = jnp.einsum("td,tdf->tf", xr, wu32[eid])
+    g = jnp.einsum("td,tdf->tf", xr, wg32)
+    u = jnp.einsum("td,tdf->tf", xr, wu32)
     h = jax.nn.silu(g) * u
-    y = jnp.einsum("tf,tfd->td", h, wd32[eid]).astype(x.dtype)
+    y = jnp.einsum("tf,tfd->td", h, wd32).astype(x.dtype)
     out = jnp.sum(y.reshape(T, k, d).astype(F32)
                   * w.reshape(T, k, 1).astype(F32), axis=1)
     return out.astype(x.dtype)

@@ -88,11 +88,31 @@ def moe_init(cfg: ModelConfig, key, n_real: int | None = None) -> dict:
     return p
 
 
+#: the expert-table leaves of a MoE layer: plain ``wg/wu/wd`` or the int8
+#: ``qexp`` subtree
+TABLE_KEYS = ("wg", "wu", "wd", "qexp")
+
+
 def n_real_experts(p: dict) -> int:
     """Number of physically stored experts (M after compression, else N)."""
     if "qexp" in p:
-        return p["qexp"]["wg"].shape[0]
-    return p["wg"].shape[0]
+        return p["qexp"]["wg"].shape[-3]
+    return p["wg"].shape[-3]
+
+
+def own_tables(p: dict) -> dict:
+    """``p`` with its own layer's ``[E, ...]`` expert tables.
+
+    A decode stack hands each layer the whole stacked ``[L, E, ...]``
+    tables plus its index ``layer`` (``transformer._scan_layers``): the
+    gather kernel reads them in place, every other path indexes its layer
+    out here — the per-layer slice the scan itself would have made."""
+    if "layer" not in p:
+        return p
+    pick = lambda a: jax.lax.dynamic_index_in_dim(  # noqa: E731
+        a, p["layer"], keepdims=False)
+    return {k: jax.tree.map(pick, v) if k in TABLE_KEYS else v
+            for k, v in p.items() if k != "layer"}
 
 
 def _quant_tables(p: dict):
@@ -301,14 +321,16 @@ def _moe_gather(cfg: ModelConfig, p: dict, xf: jax.Array, w, idx):
     Per-token weight-row gather + fused SwiGLU: no argsort/bincount/scatter,
     no per-expert segment padding — the decode-mode specialization
     (``kernels/decode_moe.py``). Per-row arithmetic and the fp32 combine
-    match :func:`_moe_ragged` exactly."""
+    match :func:`_moe_ragged` exactly. Stacked tables (``layer`` in ``p``)
+    go to the kernel whole, with the layer index."""
     from repro.kernels import ops as kops
     qt = _quant_tables(p)
+    layer = p.get("layer", 0)
     if qt is not None:
-        y = kops.gather_swiglu_q(xf, qt, idx, w.astype(F32))
+        y = kops.gather_swiglu_q(xf, qt, idx, w.astype(F32), layer)
     else:
         y = kops.gather_swiglu(xf, p["wg"], p["wu"], p["wd"], idx,
-                               w.astype(F32))
+                               w.astype(F32), layer)
     return y.astype(xf.dtype)
 
 
@@ -360,9 +382,9 @@ def moe_apply(cfg: ModelConfig, p: dict, x: jax.Array,
         if S == 1 and T <= m.gather_max_tokens:
             y = _moe_gather(cfg, p, xf, wf, rf)
         else:
-            y = _moe_ragged(cfg, p, xf, wf, rf)
+            y = _moe_ragged(cfg, own_tables(p), xf, wf, rf)
     elif m.dispatch == "ragged":
-        y = _moe_ragged(cfg, p, xf, wf, rf)
+        y = _moe_ragged(cfg, own_tables(p), xf, wf, rf)
     else:
         G = min(m.group_size, T)
         n_groups = -(-T // G)
@@ -371,7 +393,7 @@ def moe_apply(cfg: ModelConfig, p: dict, x: jax.Array,
             xf = jnp.pad(xf, ((0, pad), (0, 0)))
             wf = jnp.pad(wf, ((0, pad), (0, 0)))
             rf = jnp.pad(rf, ((0, pad), (0, 0)))
-        y = _moe_dense_groups(cfg, p,
+        y = _moe_dense_groups(cfg, own_tables(p),
                               xf.reshape(n_groups, G, d),
                               wf.reshape(n_groups, G, m.top_k),
                               rf.reshape(n_groups, G, m.top_k))

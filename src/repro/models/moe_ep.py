@@ -96,11 +96,13 @@ def moe_apply_ep(cfg: ModelConfig, p: dict, xf: jax.Array, wf: jax.Array,
     ones = jnp.ones((ep * C, 1), F32)
     e_base = me * e_loc
     qt = _quant_tables(p)
+    layer = p.get("layer", 0)
     if qt is not None:
-        y = kops.gather_swiglu_q_sharded(flat_x, qt, flat_e, ones, e_base)
+        y = kops.gather_swiglu_q_sharded(flat_x, qt, flat_e, ones, e_base,
+                                         layer)
     else:
         y = kops.gather_swiglu_sharded(flat_x, p["wg"], p["wu"], p["wd"],
-                                       flat_e, ones, e_base)
+                                       flat_e, ones, e_base, layer)
     y = y.astype(xf.dtype)               # [ep*C, d] per-pair outputs
 
     # --- return wire ------------------------------------------------------

@@ -102,29 +102,75 @@ def stack_apply(cfg: ModelConfig, stacked: dict, x, *, inv_freq,
     return y, aux, caps
 
 
+def _scan_layers(body, x, stacked: dict, *per_layer):
+    """``lax.scan`` of ``body(h, layer_p, *per_layer_slices) -> (h, ys)``
+    over the stacked layers, with the MoE expert tables held whole.
+
+    A scanned leaf reaches the body as a per-layer dynamic slice, which XLA
+    materializes before a Pallas custom call can read it: every decode step
+    would copy each layer's whole ``[E, d, f]`` tables for a gather kernel
+    that then reads a few experts of them. So the tables (``wg/wu/wd`` or
+    ``qexp``) ride into the loop as invariants, and each layer's ``moe``
+    params get them stacked plus the scanned layer index ``layer``
+    (``moe.own_tables`` slices them for every path but the gather kernel;
+    DESIGN.md §7)."""
+    moe = stacked.get("moe", {})
+    tables = {k: moe[k] for k in M.TABLE_KEYS if k in moe}
+    if tables:
+        stacked = dict(stacked, moe={k: v for k, v in moe.items()
+                                     if k not in tables})
+    n = jax.tree.leaves(stacked)[0].shape[0]
+
+    def step(h, xs):
+        layer_p, layer, *rest = xs
+        if tables:
+            layer_p = dict(layer_p, moe=dict(layer_p["moe"], **tables,
+                                             layer=layer))
+        return body(h, layer_p, *rest)
+
+    return jax.lax.scan(step, x, (stacked, jnp.arange(n, dtype=jnp.int32))
+                        + per_layer)
+
+
+def _decode_layers(cfg: ModelConfig, stacked: dict, x, attend, *kv):
+    """The serving layer body (ln1 -> attention over this layer's KV ->
+    residual -> ln2 -> MoE/MLP -> residual) scanned over the stack.
+
+    ``attend(attn_p, hn, *kv) -> (a, *kv)`` reads and writes one layer's
+    slice of each ``kv`` array (dense K/V, or paged pools and scales).
+    Decode throws the MoE aux loss away every step — ``need_aux=False``
+    skips it and the full-probs softmax it retains. Returns
+    (y, tuple of new kv arrays)."""
+    def body(h, layer_p, *kv):
+        hn = L.rmsnorm(layer_p["ln1"], h, cfg.norm_eps)
+        a, *kv = attend(layer_p["attn"], hn, *kv)
+        h = h + a
+        hn = L.rmsnorm(layer_p["ln2"], h, cfg.norm_eps)
+        if cfg.moe is not None:
+            out = M.moe_apply(cfg, layer_p["moe"], hn, need_aux=False)
+            h = h + out.y
+        else:
+            h = h + L.mlp_apply(layer_p["mlp"], hn)
+        return h, tuple(kv)
+
+    return _scan_layers(body, x, stacked, *kv)
+
+
+def _dense_kv(cfg: ModelConfig, attn_fn, stacked: dict, x, cache_k, cache_v,
+              pos, inv_freq):
+    def attend(attn_p, hn, ck, cv):
+        return attn_fn(cfg, attn_p, hn, ck, cv, pos, inv_freq=inv_freq)
+    y, (nk, nv) = _decode_layers(cfg, stacked, x, attend, cache_k, cache_v)
+    return y, nk, nv
+
+
 def stack_decode(cfg: ModelConfig, stacked: dict, x, cache_k, cache_v, pos,
                  *, inv_freq):
     """One-token decode through the scanned stack.
 
     cache_k/v: [L, B, S_max, nkv, hd]. Returns (y, new_k, new_v)."""
-    def body(h, xs):
-        layer_p, ck, cv = xs
-        hn = L.rmsnorm(layer_p["ln1"], h, cfg.norm_eps)
-        a, ck, cv = L.attn_decode(cfg, layer_p["attn"], hn, ck, cv, pos,
-                                  inv_freq=inv_freq)
-        h = h + a
-        hn = L.rmsnorm(layer_p["ln2"], h, cfg.norm_eps)
-        if cfg.moe is not None:
-            # decode throws the aux loss away every step — skip it and the
-            # full-probs softmax it retains (moe_apply need_aux=False)
-            out = M.moe_apply(cfg, layer_p["moe"], hn, need_aux=False)
-            h = h + out.y
-        else:
-            h = h + L.mlp_apply(layer_p["mlp"], hn)
-        return h, (ck, cv)
-
-    y, (nk, nv) = jax.lax.scan(body, x, (stacked, cache_k, cache_v))
-    return y, nk, nv
+    return _dense_kv(cfg, L.attn_decode, stacked, x, cache_k, cache_v, pos,
+                     inv_freq)
 
 
 def stack_decode_slots(cfg: ModelConfig, stacked: dict, x, cache_k, cache_v,
@@ -135,22 +181,8 @@ def stack_decode_slots(cfg: ModelConfig, stacked: dict, x, cache_k, cache_v,
     The MoE sub-block goes through ``moe_apply`` unchanged, so under
     ``dispatch='ragged'`` every decode step runs the grouped kernel over the
     B slot tokens. Returns (y, new_k, new_v)."""
-    def body(h, xs):
-        layer_p, ck, cv = xs
-        hn = L.rmsnorm(layer_p["ln1"], h, cfg.norm_eps)
-        a, ck, cv = L.attn_decode_slots(cfg, layer_p["attn"], hn, ck, cv, pos,
-                                        inv_freq=inv_freq)
-        h = h + a
-        hn = L.rmsnorm(layer_p["ln2"], h, cfg.norm_eps)
-        if cfg.moe is not None:
-            out = M.moe_apply(cfg, layer_p["moe"], hn, need_aux=False)
-            h = h + out.y
-        else:
-            h = h + L.mlp_apply(layer_p["mlp"], hn)
-        return h, (ck, cv)
-
-    y, (nk, nv) = jax.lax.scan(body, x, (stacked, cache_k, cache_v))
-    return y, nk, nv
+    return _dense_kv(cfg, L.attn_decode_slots, stacked, x, cache_k, cache_v,
+                     pos, inv_freq)
 
 
 def stack_verify_slots(cfg: ModelConfig, stacked: dict, x, cache_k, cache_v,
@@ -162,46 +194,25 @@ def stack_verify_slots(cfg: ModelConfig, stacked: dict, x, cache_k, cache_v,
     sub-block sees B*T tokens, so it always takes the grouped/ragged path —
     the T == 1 gather specialization never applies to a verify forward.
     Returns (y [B, T, d], new_k, new_v)."""
-    def body(h, xs):
-        layer_p, ck, cv = xs
-        hn = L.rmsnorm(layer_p["ln1"], h, cfg.norm_eps)
-        a, ck, cv = L.attn_verify_slots(cfg, layer_p["attn"], hn, ck, cv, pos,
-                                        inv_freq=inv_freq)
-        h = h + a
-        hn = L.rmsnorm(layer_p["ln2"], h, cfg.norm_eps)
-        if cfg.moe is not None:
-            out = M.moe_apply(cfg, layer_p["moe"], hn, need_aux=False)
-            h = h + out.y
-        else:
-            h = h + L.mlp_apply(layer_p["mlp"], hn)
-        return h, (ck, cv)
-
-    y, (nk, nv) = jax.lax.scan(body, x, (stacked, cache_k, cache_v))
-    return y, nk, nv
+    return _dense_kv(cfg, L.attn_verify_slots, stacked, x, cache_k, cache_v,
+                     pos, inv_freq)
 
 
-def _paged_body(cfg: ModelConfig, attn_fn, tab, pos, inv_freq, quant: bool):
-    """Layer body shared by the paged decode/verify stacks: same
-    ln1 -> attn -> residual -> ln2 -> moe/mlp structure as the dense slot
-    stacks, with the per-layer KV pool (and scales, when int8) threaded
-    through the scan carry-out."""
-    def body(h, xs):
-        if quant:
-            layer_p, kp, vp, ks, vs = xs
-        else:
-            (layer_p, kp, vp), ks, vs = xs, None, None
-        hn = L.rmsnorm(layer_p["ln1"], h, cfg.norm_eps)
-        a, kp, vp, ks, vs = attn_fn(cfg, layer_p["attn"], hn, kp, vp, ks, vs,
-                                    tab, pos, inv_freq=inv_freq)
-        h = h + a
-        hn = L.rmsnorm(layer_p["ln2"], h, cfg.norm_eps)
-        if cfg.moe is not None:
-            out = M.moe_apply(cfg, layer_p["moe"], hn, need_aux=False)
-            h = h + out.y
-        else:
-            h = h + L.mlp_apply(layer_p["mlp"], hn)
-        return h, (kp, vp, ks, vs) if quant else (kp, vp)
-    return body
+def _paged_kv(cfg: ModelConfig, attn_fn, stacked: dict, x, kp, vp, ks, vs,
+              tab, pos, inv_freq):
+    """The paged decode/verify stacks: the dense slot stacks' layer body
+    with the per-layer KV pool (and scales, when int8) threaded through the
+    scan. Returns (y, kp, vp, ks, vs), the scales None for bf16 pools."""
+    quant = ks is not None
+
+    def attend(attn_p, hn, kp, vp, ks=None, vs=None):
+        a, kp, vp, ks, vs = attn_fn(cfg, attn_p, hn, kp, vp, ks, vs, tab,
+                                    pos, inv_freq=inv_freq)
+        return (a, kp, vp, ks, vs) if quant else (a, kp, vp)
+
+    y, pools = _decode_layers(cfg, stacked, x, attend,
+                              *((kp, vp, ks, vs) if quant else (kp, vp)))
+    return (y,) + pools + ((None, None) if not quant else ())
 
 
 def stack_decode_paged(cfg: ModelConfig, stacked: dict, x, kp, vp, ks, vs,
@@ -212,14 +223,8 @@ def stack_decode_paged(cfg: ModelConfig, stacked: dict, x, kp, vp, ks, vs,
     or None (bf16 pools); tab: [B, mb] int32 (shared by all layers — one
     allocator owns the block ids); pos: [B] int32.
     Returns (y, kp, vp, ks, vs)."""
-    quant = ks is not None
-    body = _paged_body(cfg, L.attn_decode_paged, tab, pos, inv_freq, quant)
-    if quant:
-        y, (nk, nv, nks, nvs) = jax.lax.scan(body, x, (stacked, kp, vp,
-                                                       ks, vs))
-        return y, nk, nv, nks, nvs
-    y, (nk, nv) = jax.lax.scan(body, x, (stacked, kp, vp))
-    return y, nk, nv, None, None
+    return _paged_kv(cfg, L.attn_decode_paged, stacked, x, kp, vp, ks, vs,
+                     tab, pos, inv_freq)
 
 
 def stack_verify_paged(cfg: ModelConfig, stacked: dict, x, kp, vp, ks, vs,
@@ -227,14 +232,8 @@ def stack_verify_paged(cfg: ModelConfig, stacked: dict, x, kp, vp, ks, vs,
     """T-token forward over paged KV pools (speculative verify AND paged
     admission — see ``layers.attn_verify_paged``). x: [B, T, d].
     Returns (y [B, T, d], kp, vp, ks, vs)."""
-    quant = ks is not None
-    body = _paged_body(cfg, L.attn_verify_paged, tab, pos, inv_freq, quant)
-    if quant:
-        y, (nk, nv, nks, nvs) = jax.lax.scan(body, x, (stacked, kp, vp,
-                                                       ks, vs))
-        return y, nk, nv, nks, nvs
-    y, (nk, nv) = jax.lax.scan(body, x, (stacked, kp, vp))
-    return y, nk, nv, None, None
+    return _paged_kv(cfg, L.attn_verify_paged, stacked, x, kp, vp, ks, vs,
+                     tab, pos, inv_freq)
 
 
 def stack_prefill(cfg: ModelConfig, stacked: dict, x, *, inv_freq):

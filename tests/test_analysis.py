@@ -338,6 +338,28 @@ def test_contract_checker_table_extreme_ignores_unaddressed_operands():
     assert any(f.check == "bounds" for f in _findings(cap(1)))
 
 
+@pytest.mark.parametrize("shift", [(0, 0), (1, 0), (0, 1)],
+                         ids=["in-bounds", "layer+1", "expert+1"])
+def test_contract_checker_stacked_table_extremes(shift):
+    """A stacked [L, E, ...] table is addressed by two scalar tables, a
+    layer index on dim 0 and expert ids on dim 1: each is driven to its
+    own extreme (L-1, E-1), so an off-by-one on either dim is caught."""
+    L_, E, d = 2, 4, 64
+    layer = jax.ShapeDtypeStruct((1,), jnp.int32)
+    table = jax.ShapeDtypeStruct((2,), jnp.int32)
+    w = jax.ShapeDtypeStruct((L_, E, d, d), jnp.bfloat16)
+    dl, de = shift
+    cap = _capture(
+        grid=(2,), num_prefetch=2,
+        in_specs=(_spec((1, 1, d, d),
+                        lambda i, ly, ix: (ly[0] + dl, ix[i] + de, 0, 0)),),
+        operands=(layer, table, w),
+        out_spec=_spec((32, d), lambda i, ly, ix: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((64, d), jnp.bfloat16))
+    bounds = [f for f in _findings(cap) if f.check == "bounds"]
+    assert bool(bounds) == (shift != (0, 0)), bounds
+
+
 # ---------------------------------------------------------------------------
 # trace guard
 # ---------------------------------------------------------------------------

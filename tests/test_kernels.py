@@ -4,6 +4,8 @@ int8 sweeps assert BITWISE equality with the jnp dequant oracles
 (DESIGN.md §8): the kernels keep the dequantized weights at fp32 with a
 single output-side downcast, so there is no rounding XLA can cancel or
 contract out from under the comparison."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -298,6 +300,40 @@ def test_gather_property(T, E, k, seed):
     np.testing.assert_allclose(
         np.asarray(y), np.asarray(ref.gather_swiglu(x, wg, wu, wd, idx, w)),
         atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("ids", ["random", "duplicate"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_gather_stacked_tables_bitwise(quantized, dtype, ids):
+    """The gather kernels read layer ``l`` of stacked [L, E, ...] tables in
+    place: for every l the result is BITWISE the per-layer call on
+    ``table[l]`` (the L = 1 case), in the kernel and in the oracle the CPU
+    serving path runs — duplicate top-k ids included."""
+    n_layers, T, d, f, E, k = 3, 5, 16, 32, 4, 2
+    layers = [_gather_inputs(T, d, f, E, k, dtype, seed=l)
+              for l in range(n_layers)]
+    x, _, _, _, idx, w = layers[0]
+    if ids == "duplicate":
+        idx = jnp.asarray([[1, 1], [2, 0], [3, 3], [0, 0], [2, 2]],
+                          jnp.int32)
+    wg, wu, wd = (jnp.stack([t[i] for t in layers]) for i in (1, 2, 3))
+    if quantized:
+        stack = Q.quantize_expert_tables(wg, wu, wd)
+        kern = functools.partial(K_dm.gather_swiglu_q, interpret=True)
+        orac = ref.gather_swiglu_q
+    else:
+        stack = (wg, wu, wd)
+        kern = lambda x, t, *a: K_dm.gather_swiglu(  # noqa: E731
+            x, *t, *a, interpret=True)
+        orac = lambda x, t, *a: ref.gather_swiglu(x, *t, *a)  # noqa: E731
+    for l in range(n_layers):
+        own = jax.tree.map(lambda a: a[l], stack)
+        for fn in (kern, orac):
+            y = fn(x, stack, idx, w, jnp.int32(l))
+            np.testing.assert_array_equal(
+                np.asarray(y, np.float32),
+                np.asarray(fn(x, own, idx, w), np.float32))
 
 
 # ---------------------------------------------------------------------------
